@@ -31,8 +31,7 @@ DISABLE_RE = re.compile(r"#\s*repro-lint:\s*disable=([A-Za-z0-9_,\s]+)")
 # transforms whose function arguments run under a jax trace
 _JIT_WRAPPERS = {"jax.jit", "jit", "jax.make_jaxpr", "make_jaxpr",
                  "jax.pmap", "pmap", "jax.checkpoint", "jax.remat"}
-_SHARD_WRAPPERS = {"shard_map", "_shard_map", "jax.shard_map",
-                   "jax.experimental.shard_map.shard_map"}
+_SHARD_WRAPPERS = {"shard_map", "jax.shard_map"}
 # (callee suffix, positions of function-valued args); None = all args
 _BRANCH_WRAPPERS = {
     "lax.cond": (1, 2), "lax.switch": None, "lax.scan": (1,),

@@ -61,7 +61,7 @@ class SortInShardMapRule:
     FIXTURE_BAD = '''
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _regroup(x, flat_e):
@@ -80,7 +80,7 @@ def run(mesh, x, e):
     FIXTURE_GOOD = '''
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _regroup(x, counts):

@@ -38,7 +38,7 @@ import json
 from collections import Counter
 from typing import Optional
 
-# jaxpr collective primitive -> census kind (jax 0.4.x names; note
+# jaxpr collective primitive -> census kind (note
 # lax.psum_scatter lowers to the primitive named "reduce_scatter")
 _PRIM_KIND = {
     "psum": "all_reduce",
@@ -338,10 +338,11 @@ def run_contract(arch: Optional[str], mesh_shape: tuple, strategies,
 
     from repro.core.cost_model import EpOverlap
     from repro.core.partitioner import make_plan
+    from repro.launch.mesh import make_mesh
 
     ep_overlap = EpOverlap(chunks=max(1, chunks), cap_rows=cap_rows) \
         if chunks > 1 or cap_rows >= 0 else None
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
 
     if arch is None:
         cfg = _tiny_cfg()

@@ -61,7 +61,7 @@ from repro.configs.base import ModelConfig
 from repro.core import analyzer
 from repro.core import cost_model as cm
 from repro.core.topology import (CLUSTERS, TPU_V5E_MULTIPOD, TPU_V5E_POD,
-                                 ClusterSpec)
+                                 ClusterSpec, cluster_for_device_kind)
 from repro.kernels import autotune
 
 AUTO = "auto"
@@ -118,11 +118,19 @@ def resolve_cluster(cluster: Union[str, ClusterSpec, None] = None, *,
                     mesh=None) -> tuple[ClusterSpec, str]:
     """(ClusterSpec, provenance).  Explicit name/spec wins — validated
     against ``mesh.devices.size`` when a mesh is given; ``auto``/None falls
-    back to the v5e heuristic (multi-pod iff the mesh exceeds one pod)."""
+    back to the v5e heuristic (multi-pod iff the mesh exceeds one pod).
+    With no mesh on a TPU backend the attached chip decides: its
+    ``device_kind`` names a one-chip cluster, and a kind with no
+    ClusterSpec raises.  Elsewhere (the CPU host) the analyzer's target is
+    the v5e pod."""
     if cluster is None or cluster == AUTO:
         if mesh is not None:
             spec = TPU_V5E_MULTIPOD if mesh.devices.size > 256 else TPU_V5E_POD
             return spec, f"auto:mesh-heuristic({mesh.devices.size} devices)"
+        import jax
+        if jax.default_backend() == "tpu":
+            kind = jax.devices()[0].device_kind
+            return cluster_for_device_kind(kind), f"auto:device_kind({kind})"
         return TPU_V5E_POD, "auto:default(v5e-pod-256)"
     if isinstance(cluster, str):
         if cluster not in CLUSTERS:

@@ -118,6 +118,23 @@ CLUSTERS = {
     c.name: c for c in (H20_CLUSTER, ASCEND_910B_CLUSTER, TPU_V5E_POD, TPU_V5E_MULTIPOD)
 }
 
+# ``jax.devices()[0].device_kind`` -> the ClusterSpec whose per-chip
+# constants describe that chip.  A kind missing here is an error, never a
+# default: the cost model would price a chip it does not know.
+DEVICE_KIND_CLUSTERS = {
+    "TPU v5 lite": TPU_V5E_POD,       # what jax reports for a v5e chip
+}
+
+
+def cluster_for_device_kind(kind: str) -> ClusterSpec:
+    """The one-chip cluster of the chip JAX names ``kind``."""
+    base = DEVICE_KIND_CLUSTERS.get(kind)
+    if base is None:
+        raise KeyError(f"no ClusterSpec for device kind {kind!r} "
+                       f"(have {sorted(DEVICE_KIND_CLUSTERS)})")
+    chip = base.name.split("-")[0]
+    return dataclasses.replace(base, name=f"{chip}-1", n_node=1, n_proc=1)
+
 
 def is_pow2(x: int) -> bool:
     return x >= 1 and (x & (x - 1)) == 0
@@ -149,6 +166,8 @@ __all__ = [
     "TPU_V5E_POD",
     "TPU_V5E_MULTIPOD",
     "CLUSTERS",
+    "DEVICE_KIND_CLUSTERS",
+    "cluster_for_device_kind",
     "is_pow2",
     "pow2_divisors",
 ]

@@ -32,6 +32,7 @@ import dataclasses
 import json
 import os
 import tempfile
+import warnings
 from typing import Callable, Optional
 
 import numpy as _np
@@ -222,8 +223,9 @@ def _default_blocks(op: str, shape: tuple, dtype: str) -> dict:
                 bd = max(_MXU, bd // 2)
         return {"bn": bn, "bd": bd, "bh": bh}
     if op in ("permute", "unpermute"):
-        # n output rows per grid step; the gather source stays VMEM-resident,
-        # so the block only covers the output tile + index/weight columns.
+        # n output rows per grid step; the gather source stays in HBM (rows
+        # arrive by DMA), so VMEM holds only the tile's rows: the zero tile
+        # (permute) or the k fetched rows plus the output tile (unpermute).
         n, h = shape[0], shape[1]
         bn = 8
         while bn * 2 <= n and bn * 2 * h * el <= VMEM_BUDGET_BYTES // 8:
@@ -356,23 +358,28 @@ def tune(op: str, fn: Callable, candidates: list[dict], *args,
         shape = _key_shape(op, args)
     if dtype is None:
         dtype = args[0].dtype
-    best, best_t = None, float("inf")
+    best, best_t, error = None, float("inf"), None
     for blocks in candidates:
         try:
             for _ in range(warmup):
                 jax.block_until_ready(fn(*args, **blocks))
-            ts = []
-            for _ in range(iters):
-                t0 = _time.perf_counter()
-                jax.block_until_ready(fn(*args, **blocks))
-                ts.append(_time.perf_counter() - t0)
-            t = sorted(ts)[len(ts) // 2]
-        except Exception:
+        except Exception as e:        # a block the compiler refuses
+            error = e
+            warnings.warn(f"autotune {op}{tuple(shape)}: candidate {blocks} "
+                          f"failed: {type(e).__name__}: {e}")
             continue
+        ts = []
+        for _ in range(iters):
+            t0 = _time.perf_counter()
+            jax.block_until_ready(fn(*args, **blocks))
+            ts.append(_time.perf_counter() - t0)
+        t = sorted(ts)[len(ts) // 2]
         if t < best_t:
             best, best_t = blocks, t
     if best is None:
-        best = _default_blocks(op, tuple(shape), str(dtype))
+        raise RuntimeError(
+            f"autotune {op}{tuple(shape)}: none of {len(candidates)} "
+            f"candidates ran") from error
     register(op, shape, dtype, best)
     return dict(best)
 

@@ -5,11 +5,16 @@ prefill/decode serving iteration: slot ``i`` contributes ``q_len[i]`` query
 rows (a prefill chunk, one decode token, or 0 = idle) at absolute cache
 offset ``q_offset[i]`` against a cache whose valid prefix is ``kv_len[i]``.
 
-Grid ``(batch, kv_head, q-tile, S-tile)``; the S dimension is the innermost
+Grid ``(batch, q-tile, S-tile)``; the S dimension is the innermost
 sequential axis so the online-softmax state (m, l, acc) lives in VMEM
 scratch across KV tiles (same schedule as the old ``flash_decode``, which
 is now the ``sq == 1`` specialization of this kernel — see
-``flash_decode.py``).  Per-slot ``q_offset``/``q_len``/``kv_len`` arrive
+``flash_decode.py``).  Blocks are token-major and hold EVERY kv head:
+q ``(bq, nq, hd)``, k/v ``(bs, nkv, hd)``.  Their last two dims are then the
+array's own, which is what the TPU tiling rule accepts for any head count
+(a one-head ``(bs, 1, hd)`` block of an 8-head cache is refused), so the
+cache keeps its ``(…, S|page, nkv, hd)`` layout and the kernel walks the kv
+heads in an unrolled loop, each against its ``g = nq // nkv`` query heads.  Per-slot ``q_offset``/``q_len``/``kv_len`` arrive
 via scalar prefetch, so raggedness is handled at *tile* granularity:
 
   * KV tiles past a slot's causal frontier (``q_offset + q_tile_hi``) or
@@ -43,8 +48,8 @@ NEG_INF = -1e30
 
 def _flash_chunk_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref,
                         m_ref, l_ref, acc_ref, *,
-                        bq: int, bs: int, g: int, scale: float):
-    bi, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+                        bq: int, bs: int, g: int, nkv: int, scale: float):
+    bi, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     q_off = meta_ref[0, bi]
     q_len = meta_ref[1, bi]
     kv_len = meta_ref[2, bi]
@@ -62,36 +67,39 @@ def _flash_chunk_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when((row_hi > 0) & (j * bs < kv_limit))
     def _tile():
-        q = q_ref[0, :, 0].astype(jnp.float32)           # (bq, g, hd)
-        q = q.reshape(bq * g, q.shape[-1]) * scale
-        k = k_ref[0, :, 0].astype(jnp.float32)           # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)           # (bs, hdv)
-
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (bq*g, bs)
         row = (qi * bq
                + jax.lax.broadcasted_iota(jnp.int32, (bq * g, bs), 0) // g)
         pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bq * g, bs), 1)
         mask = (row < q_len) & (pos < kv_len) & (pos <= q_off + row)
-        s = jnp.where(mask, s, NEG_INF)
+        for h in range(nkv):                  # kv head h serves q heads h*g..
+            q = q_ref[:, h * g:(h + 1) * g, :].astype(jnp.float32)  # (bq,g,hd)
+            q = q.reshape(bq * g, q.shape[-1]) * scale
+            k = k_ref[:, h, :].astype(jnp.float32)                  # (bs, hd)
+            v = v_ref[:, h, :].astype(jnp.float32)                  # (bs, hdv)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))  # (bq*g, 1)
-        # all-masked rows have m_new == NEG_INF and exp(s - m_new) == 1;
-        # re-masking p keeps their l at 0 so the flush emits exact zeros
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, NEG_INF)                         # (bq*g, bs)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))  # (bq*g, 1)
+            # all-masked rows have m_new == NEG_INF and exp(s - m_new) == 1;
+            # re-masking p keeps their l at 0 so the flush emits exact zeros
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + p.sum(-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _flush():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, :, 0] = out.reshape(bq, g, -1).astype(o_ref.dtype)
+        for h in range(nkv):
+            out = acc_ref[h] / jnp.maximum(l_ref[h], 1e-20)
+            o_ref[:, h * g:(h + 1) * g, :] = out.reshape(
+                bq, g, -1).astype(o_ref.dtype)
 
 
-def _kv_tile_index(bi, hi, qi, j, m, *, bq: int, bs: int):
+def _kv_tile_index(bi, qi, j, m, *, bq: int, bs: int):
     """Clamp the KV tile index to the slot's frontier tile.
 
     Past the frontier the compute is pl.when-gated off anyway; clamping the
@@ -102,7 +110,14 @@ def _kv_tile_index(bi, hi, qi, j, m, *, bq: int, bs: int):
     row_hi = jnp.minimum(m[1, bi] - qi * bq, bq)
     kv_limit = jnp.minimum(m[2, bi], m[0, bi] + qi * bq + row_hi)
     last = jnp.maximum((kv_limit - 1) // bs, 0)
-    return bi, jnp.minimum(j, last), hi, 0
+    return bi, jnp.minimum(j, last), 0, 0
+
+
+def _scratch(nkv: int, rows: int, hdv: int) -> list:
+    """Per-kv-head online-softmax state: running max m, sum l, and acc."""
+    return [pltpu.VMEM((nkv, rows, 1), jnp.float32),
+            pltpu.VMEM((nkv, rows, 1), jnp.float32),
+            pltpu.VMEM((nkv, rows, hdv), jnp.float32)]
 
 
 @functools.partial(jax.jit,
@@ -126,9 +141,8 @@ def flash_chunk(q, k, v, q_offset, q_len, kv_len, *, bq: int = 128,
     bq = min(bq, sq)
     bs = min(bs, skv)
     pq, ps = (-sq) % bq, (-skv) % bs
-    qg = q.reshape(b, sq, nkv, g, hd)
     if pq:
-        qg = jnp.pad(qg, ((0, 0), (0, pq), (0, 0), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
     if ps:
         k = jnp.pad(k, ((0, 0), (0, ps), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, ps), (0, 0), (0, 0)))
@@ -143,27 +157,25 @@ def flash_chunk(q, k, v, q_offset, q_len, kv_len, *, bq: int = 128,
     kv_index = functools.partial(_kv_tile_index, bq=bq, bs=bs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, nkv, sqp // bq, sp // bs),
+        grid=(b, sqp // bq, sp // bs),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, g, hd),
-                         lambda bi, hi, qi, j, m: (bi, qi, hi, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_index),
-            pl.BlockSpec((1, bs, 1, hdv), kv_index),
+            pl.BlockSpec((None, bq, nq, hd),
+                         lambda bi, qi, j, m: (bi, qi, 0, 0)),
+            pl.BlockSpec((None, bs, nkv, hd), kv_index),
+            pl.BlockSpec((None, bs, nkv, hdv), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, g, hdv),
-                               lambda bi, hi, qi, j, m: (bi, qi, hi, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((bq * g, 1), jnp.float32),
-                        pltpu.VMEM((bq * g, 1), jnp.float32),
-                        pltpu.VMEM((bq * g, hdv), jnp.float32)],
+        out_specs=pl.BlockSpec((None, bq, nq, hdv),
+                               lambda bi, qi, j, m: (bi, qi, 0, 0)),
+        scratch_shapes=_scratch(nkv, bq * g, hdv),
     )
     out = pl.pallas_call(
-        functools.partial(_flash_chunk_kernel, bq=bq, bs=bs, g=g,
+        functools.partial(_flash_chunk_kernel, bq=bq, bs=bs, g=g, nkv=nkv,
                           scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sqp, nkv, g, hdv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, sqp, nq, hdv), q.dtype),
         interpret=interpret,
-    )(meta, qg, k, v)
-    return out[:, :sq].reshape(b, sq, nq, hdv)
+    )(meta, q, k, v)
+    return out[:, :sq]
 
 
 # ---------------------------------------------------------------------------
@@ -177,7 +189,7 @@ def _paged_kernel(meta_ref, bt_ref, *rest, **kw):
     _flash_chunk_kernel(meta_ref, *rest, **kw)
 
 
-def _paged_kv_tile_index(bi, hi, qi, j, m, bt, *, bq: int, bs: int, ps: int):
+def _paged_kv_tile_index(bi, qi, j, m, bt, *, bq: int, bs: int, ps: int):
     """Frontier-clamped logical KV tile ``j`` -> (page, tile-in-page).
 
     Same causal-frontier clamp as ``_kv_tile_index`` (dead tiles re-request
@@ -192,7 +204,7 @@ def _paged_kv_tile_index(bi, hi, qi, j, m, bt, *, bq: int, bs: int, ps: int):
     jj = jnp.minimum(j, last)
     tpp = ps // bs                    # KV tiles per page
     page = jnp.maximum(bt[bi, jj // tpp], 0)
-    return page, jj % tpp, hi, 0
+    return page, jj % tpp, 0, 0
 
 
 @functools.partial(jax.jit,
@@ -224,9 +236,8 @@ def flash_chunk_paged(q, k_pages, v_pages, block_tables, q_offset, q_len,
         raise ValueError(f"bs {bs} must divide the page size {page}")
     bq = min(bq, sq)
     pq = (-sq) % bq
-    qg = q.reshape(b, sq, nkv, g, hd)
     if pq:
-        qg = jnp.pad(qg, ((0, 0), (0, pq), (0, 0), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
     sqp = sq + pq
 
     meta = jnp.stack([
@@ -238,26 +249,25 @@ def flash_chunk_paged(q, k_pages, v_pages, block_tables, q_offset, q_len,
     kv_index = functools.partial(_paged_kv_tile_index, bq=bq, bs=bs, ps=page)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,        # meta (3, B) + block tables (B, nb)
-        grid=(b, nkv, sqp // bq, nb * (page // bs)),
+        grid=(b, sqp // bq, nb * (page // bs)),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, g, hd),
-                         lambda bi, hi, qi, j, m, bt: (bi, qi, hi, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_index),
-            pl.BlockSpec((1, bs, 1, hdv), kv_index),
+            pl.BlockSpec((None, bq, nq, hd),
+                         lambda bi, qi, j, m, bt: (bi, qi, 0, 0)),
+            pl.BlockSpec((None, bs, nkv, hd), kv_index),
+            pl.BlockSpec((None, bs, nkv, hdv), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, g, hdv),
-                               lambda bi, hi, qi, j, m, bt: (bi, qi, hi, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((bq * g, 1), jnp.float32),
-                        pltpu.VMEM((bq * g, 1), jnp.float32),
-                        pltpu.VMEM((bq * g, hdv), jnp.float32)],
+        out_specs=pl.BlockSpec((None, bq, nq, hdv),
+                               lambda bi, qi, j, m, bt: (bi, qi, 0, 0)),
+        scratch_shapes=_scratch(nkv, bq * g, hdv),
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, bq=bq, bs=bs, g=g, scale=scale),
+        functools.partial(_paged_kernel, bq=bq, bs=bs, g=g, nkv=nkv,
+                          scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sqp, nkv, g, hdv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, sqp, nq, hdv), q.dtype),
         interpret=interpret,
-    )(meta, block_tables.astype(jnp.int32), qg, k_pages, v_pages)
-    return out[:, :sq].reshape(b, sq, nq, hdv)
+    )(meta, block_tables.astype(jnp.int32), q, k_pages, v_pages)
+    return out[:, :sq]
 
 
 __all__ = ["flash_chunk", "flash_chunk_paged"]

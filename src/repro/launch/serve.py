@@ -24,6 +24,7 @@ import argparse
 
 import repro.configs as C
 from repro.core.topology import CLUSTERS
+from repro.launch import compile_cache
 from repro.serving.api import AUTO, LLM, ServeSpec, SpeculationConfig
 from repro.serving.scheduler import synthetic_workload
 
@@ -129,6 +130,7 @@ def build_spec(args: argparse.Namespace) -> ServeSpec:
 def main(argv=None):
     args = parse_args(argv)
     spec = build_spec(args)
+    print(f"[compile cache] {compile_cache.enable()}")
 
     # ---- offline stage: automatic analyzer + knob resolution on the
     # FULL config (model + cluster in, serving configuration out) ----

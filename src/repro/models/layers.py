@@ -20,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.core.partitioner import NULL_PLAN, ShardingPlan
@@ -276,6 +277,42 @@ def gather_pages(pool, block_table):
     return pages.reshape(b, nb * ps, *pool.shape[2:])
 
 
+def paged_flash_attention(plan: ShardingPlan, q, k_pool, v_pool, block_table,
+                          q_offset, q_len, kv_len):
+    """The ``flash_chunk_paged`` kernel, one call per device on a mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so on a mesh the call runs under
+    ``shard_map``: each device takes its batch slots (the plan's "batch"
+    axes) and its group of heads (the "heads" axes, when the kv heads split
+    the same way, or there is one latent kv head).  Pools stay whole on the
+    batch axes, because any slot may own any page."""
+    from repro.kernels import ops as _kops
+    scale = float(q.shape[-1] ** -0.5)
+    if not plan.enabled:
+        return _kops.flash_chunk_paged(q, k_pool, v_pool, block_table,
+                                       q_offset, q_len, kv_len, scale=scale)
+    b, _, nq, _ = q.shape
+    nkv = k_pool.shape[2]
+    bspec = plan.spec_for_shape((b,), ("batch",))
+    b_ax = bspec[0] if len(bspec) else None
+    hspec = plan.spec_for_shape((nq,), ("heads",))
+    h_ax = hspec[0] if len(hspec) else None
+    tp = plan.axis_size(h_ax if isinstance(h_ax, tuple) else
+                        ((h_ax,) if h_ax else ()))
+    if nkv > 1 and nkv % tp:
+        h_ax = None                  # kv heads cannot follow: keep all heads
+    kv_ax = h_ax if nkv > 1 else None
+    S = PartitionSpec
+    fn = functools.partial(_kops.flash_chunk_paged, scale=scale)
+    return jax.shard_map(
+        fn, mesh=plan.mesh,
+        in_specs=(S(b_ax, None, h_ax, None), S(None, None, kv_ax, None),
+                  S(None, None, kv_ax, None), S(b_ax, None), S(b_ax),
+                  S(b_ax), S(b_ax)),
+        out_specs=S(b_ax, None, h_ax, None), check_vma=False,
+    )(q, k_pool, v_pool, block_table, q_offset, q_len, kv_len)
+
+
 def decode_attention(q, k, v, *, kv_len=None, q_positions=None, window: int = 0,
                      k_positions: Optional[jax.Array] = None,
                      scale: Optional[float] = None,
@@ -428,9 +465,8 @@ def gqa_attention(p, x, cfg: ModelConfig, plan: ShardingPlan = NULL_PLAN, *,
         kv_len = idx + q_lens
         pol = plan.kernels
         if pol is not None and pol.flash_chunk:
-            from repro.kernels import ops as _kops
-            out = _kops.flash_chunk_paged(q, kc, vc, bt, idx, q_lens, kv_len,
-                                          scale=float(q.shape[-1] ** -0.5))
+            out = paged_flash_attention(plan, q, kc, vc, bt, idx, q_lens,
+                                        kv_len)
         else:
             # gathered view is shape-identical to the dense buffer, so the
             # jnp fallback routes EXACTLY like the dense path (bit-identical

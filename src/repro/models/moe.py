@@ -71,14 +71,6 @@ from repro.kernels.policy import NULL_POLICY, KernelPolicy
 from repro.models.layers import activate, rms_norm
 from repro.models.param import P
 
-try:                                   # jax >= 0.6: public API
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except AttributeError:                 # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
 # ---------------------------------------------------------------------------
 # Spec
 # ---------------------------------------------------------------------------
@@ -424,26 +416,19 @@ def moe_local(p, x, cfg: ModelConfig, cf: Optional[float] = None,
 # Distributed paths (shard_map)
 # ---------------------------------------------------------------------------
 
-def _axis_sz(a):
-    try:
-        return jax.lax.axis_size(a)
-    except AttributeError:       # jax 0.4.x: psum of a literal folds to size
-        return jax.lax.psum(1, a)
-
-
 def _axis_index(axes: tuple):
     if not axes:
         return 0
     idx = jax.lax.axis_index(axes[0])
     for a in axes[1:]:
-        idx = idx * _axis_sz(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
 def _axis_size(axes: tuple):
     s = 1
     for a in axes:
-        s *= _axis_sz(a)
+        s *= jax.lax.axis_size(a)
     return s
 
 
@@ -933,11 +918,11 @@ def moe_block(p, x, cfg: ModelConfig, plan: ShardingPlan = NULL_PLAN, *,
     out_specs = (x_spec, PartitionSpec())
     if with_stats:
         out_specs = out_specs + (PartitionSpec(),)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(p_specs, x_spec),
         out_specs=out_specs,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(p, x)
 
 

@@ -10,6 +10,7 @@ logical axes in one declaration is what lets the weight loader/partitioner
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -30,6 +31,13 @@ class P:
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "scale"))
+def _normal(key, shape: tuple, dtype, scale: float):
+    # one fused program per tensor, drawn in the target dtype: the device
+    # holds the result and nothing the size of a float32 copy of it
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(scale, dtype)
+
+
 def _init_one(key, p: P, dtype):
     if p.init == "zeros":
         return jnp.zeros(p.shape, dtype)
@@ -37,7 +45,7 @@ def _init_one(key, p: P, dtype):
         return jnp.ones(p.shape, dtype)
     fan_in = p.shape[-2] if len(p.shape) >= 2 else p.shape[-1]
     scale = p.scale if p.scale is not None else 1.0 / math.sqrt(max(fan_in, 1))
-    return (jax.random.normal(key, p.shape, jnp.float32) * scale).astype(dtype)
+    return _normal(key, tuple(p.shape), jnp.dtype(dtype), float(scale))
 
 
 def is_p(x) -> bool:

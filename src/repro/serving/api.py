@@ -435,7 +435,6 @@ class LLM:
     def __init__(self, cfg: ModelConfig, params, spec: ResolvedServeSpec, *,
                  embeds_fn=None, dtype=None):
         import jax.numpy as jnp
-        dtype = jnp.float32 if dtype is None else dtype
         if embeds_fn is None and cfg.frontend == "audio_stub":
             e = cfg.encoder
             embeds_fn = lambda b: {"frames": jnp.full(
@@ -456,13 +455,17 @@ class LLM:
         ``cfg``/``params`` override the registry lookup (custom configs,
         pre-initialized weights); with a plain ``ServeSpec`` the FULL
         config is analysed and the reduced/full engine config follows
-        ``spec.reduced``.
+        ``spec.reduced``.  Weights drawn here (``params=None``) take
+        ``dtype``, by default float32 for the reduced engine and
+        ``cfg.dtype`` for the full one.  On a mesh every weight is placed
+        by the resolved plan's shardings.
         """
         import jax
         import jax.numpy as jnp
 
         import repro.configs as C
         from repro.models.model import init_params
+        from repro.serving.weights import param_shardings
 
         if isinstance(spec, ServeSpec):
             full = cfg if (cfg is not None and spec.arch is None) else None
@@ -474,10 +477,17 @@ class LLM:
                 raise ValueError("pass cfg= for a spec without .arch")
             cfg = (C.get_reduced(resolved.arch) if resolved.reduced
                    else C.get(resolved.arch))
-        dtype = jnp.float32 if dtype is None else dtype
         if params is None:
+            if dtype is None:
+                # the full model holds its weights in its own dtype; the
+                # reduced variant is the float32 correctness model
+                dtype = (jnp.float32 if resolved.reduced
+                         else jnp.dtype(cfg.dtype))
             params = init_params(jax.random.PRNGKey(resolved.seed), cfg,
                                  dtype)
+        if resolved.plan.enabled:       # each weight onto its plan shards
+            params = jax.device_put(params,
+                                    param_shardings(cfg, resolved.plan))
         return cls(cfg, params, resolved, embeds_fn=embeds_fn, dtype=dtype)
 
     # -- streaming -------------------------------------------------------

@@ -159,15 +159,19 @@ def unified_supported(cfg: ModelConfig) -> bool:
 
 class Engine:
     def __init__(self, cfg: ModelConfig, params, spec=None, *,
-                 embeds_fn: Optional[Callable] = None, dtype=jnp.float32):
+                 embeds_fn: Optional[Callable] = None, dtype=None):
         # ``spec`` (a serving.api.ResolvedServeSpec) is THE configuration
         # surface: strategy/plan, kernels, dispatch, chunk, token budget,
         # the slot envelope, overload policy and fault plan all ride on it.
+        # ``dtype`` (the KV state's) defaults to the weights' own dtype.
         if spec is None:
             raise TypeError(
                 "Engine needs a resolved spec: Engine(cfg, params, "
                 "ServeSpec(...).resolve()) — the per-knob kwargs shim was "
                 "removed after its one-release window (docs/api.md)")
+        if dtype is None:
+            dtype = next(x.dtype for x in jax.tree.leaves(params)
+                         if jnp.issubdtype(x.dtype, jnp.floating))
         self.spec = spec
         self.cfg, self.params, self.plan = cfg, params, spec.plan
         self.max_batch, self.max_len = spec.max_batch, spec.max_len

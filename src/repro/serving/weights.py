@@ -100,6 +100,18 @@ def export_llama_style(params, cfg: ModelConfig) -> dict:
     return flat
 
 
+def param_shardings(cfg: ModelConfig, plan: ShardingPlan):
+    """The plan's NamedSharding for every weight (divisibility-aware, as
+    ``load_llama_style`` places them), or None without a mesh."""
+    if not plan.enabled:
+        return None
+    shapes = jax.tree.map(lambda p: p.shape, model_spec(cfg), is_leaf=is_p)
+    return jax.tree.map(lambda shape, ax: plan.sharding_for(shape, ax),
+                        shapes, param_axes(cfg),
+                        is_leaf=lambda t: isinstance(t, tuple)
+                        and all(isinstance(d, int) for d in t))
+
+
 def load_llama_style(flat: dict, cfg: ModelConfig,
                      plan: ShardingPlan = NULL_PLAN,
                      dtype=jnp.float32) -> dict:
@@ -140,4 +152,4 @@ def load_llama_style(flat: dict, cfg: ModelConfig,
     return out
 
 
-__all__ = ["export_llama_style", "load_llama_style"]
+__all__ = ["export_llama_style", "load_llama_style", "param_shardings"]

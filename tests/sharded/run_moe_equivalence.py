@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.partitioner import make_plan
+from repro.launch.mesh import make_mesh
 from repro.models import moe as M
 from repro.models.param import init_tree
 
@@ -27,10 +28,10 @@ def main():
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64), jnp.float32)
 
     meshes = {
-        "2x2": jax.make_mesh((2, 2), ("data", "model")),
-        "2x4": jax.make_mesh((2, 4), ("data", "model")),
-        "4x2": jax.make_mesh((4, 2), ("data", "model")),
-        "pod2x2x2": jax.make_mesh((2, 2, 2), ("pod", "data", "model")),
+        "2x2": make_mesh((2, 2), ("data", "model")),
+        "2x4": make_mesh((2, 4), ("data", "model")),
+        "4x2": make_mesh((4, 2), ("data", "model")),
+        "pod2x2x2": make_mesh((2, 2, 2), ("pod", "data", "model")),
     }
     cases = [("mixserve", "fused"), ("mixserve", "sync"),
              ("mixserve", "unfused"), ("dp_ep", "unfused"),

@@ -17,6 +17,7 @@ from repro.configs.base import ModelConfig
 from repro.core.partitioner import make_plan
 from repro.kernels import ops
 from repro.kernels.policy import KernelPolicy
+from repro.launch.mesh import make_mesh
 from repro.models import moe as M
 from repro.models.param import init_tree
 
@@ -43,7 +44,7 @@ def main():
     params = init_tree(jax.random.PRNGKey(0), M.moe_spec(cfg), jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64), jnp.float32)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cases = [("mixserve", "fused"), ("mixserve", "unfused"),
              ("dp_ep", "unfused"), ("pure_tp", "unfused")]
     for mode in ("capacity", "dropless"):

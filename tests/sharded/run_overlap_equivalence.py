@@ -8,7 +8,12 @@ skew that overflows a tight cap and must take the worst-case-extent
 fallback (rank-uniform lax.cond) without changing a single bit."""
 
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+# XLA:CPU's YNNPACK dot fusions pick their accumulation order by row count,
+# so the same row's product differs between a chunk-sized and a whole-batch
+# GEMM; the plain dot emitter computes each row the same way at any row
+# count, which is what a bit-for-bit comparison of two schedules needs.
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                           "--xla_cpu_experimental_ynn_fusion_type=")
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +22,7 @@ from repro.configs.base import ModelConfig
 from repro.core.cost_model import EpOverlap, cap_rows_for
 from repro.core.partitioner import make_plan
 from repro.kernels.policy import KernelPolicy
+from repro.launch.mesh import make_mesh
 from repro.models import moe as M
 from repro.models.param import init_tree
 
@@ -30,8 +36,8 @@ def main():
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 64), jnp.float32)
 
     meshes = {
-        "2x4": jax.make_mesh((2, 4), ("data", "model")),   # ep=2, tp=4
-        "4x2": jax.make_mesh((4, 2), ("data", "model")),   # ep=4, tp=2
+        "2x4": make_mesh((2, 4), ("data", "model")),   # ep=2, tp=4
+        "4x2": make_mesh((4, 2), ("data", "model")),   # ep=4, tp=2
     }
 
     def run(mesh, strat, algo, ovl, p=params, xx=x, kernels=None):

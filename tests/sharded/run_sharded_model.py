@@ -14,6 +14,7 @@ import numpy as np
 
 import repro.configs as C
 from repro.core.partitioner import make_plan
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 
 # Per-arch max-abs-error gates.  The default is the strict 2e-4 every arch
@@ -94,13 +95,13 @@ def check(arch, mesh, plan_name="mixserve"):
 
 
 def main():
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     for arch in ("smollm-360m", "deepseek-v2-236b", "phi3.5-moe-42b",
                  "recurrentgemma-9b", "rwkv6-1.6b", "qwen2-vl-7b",
                  "whisper-tiny", "minicpm3-4b"):
         check(arch, mesh)
     # multi-pod mini mesh on the paper-representative arch
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     check("deepseek-v2-236b", mesh3)
     check("phi3.5-moe-42b", mesh3, plan_name="dp_ep")
     print("SHARDED_MODEL_OK")

@@ -48,7 +48,7 @@ _HISTORICAL_ARGSORT = '''
 import functools
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _moe_shard_fn(p, x, *, cfg, tp_axes, ep_axes):
@@ -62,7 +62,7 @@ def moe_block(p, x, cfg, plan):
     fn = functools.partial(_moe_shard_fn, cfg=cfg, tp_axes=plan.tp_axes,
                            ep_axes=plan.ep_axes)
     return shard_map(fn, mesh=plan.mesh, in_specs=(None, None),
-                     out_specs=None, check_rep=False)(p, x)
+                     out_specs=None, check_vma=False)(p, x)
 '''
 
 
@@ -185,9 +185,9 @@ def test_jaxpr_census_walker_counts_and_cond():
     from jax.sharding import PartitionSpec as P
 
     from repro.analysis.trace_contract import jaxpr_census
-    from repro.models.moe import _SHARD_MAP_KW, _shard_map
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def body(x):
         y = jax.lax.psum(x, "model")
@@ -203,8 +203,8 @@ def test_jaxpr_census_walker_counts_and_cond():
 
         return jax.lax.cond(y.sum() > 0, hot, fallback, y)
 
-    f = _shard_map(body, mesh=mesh, in_specs=P("data", "model"),
-                   out_specs=P("data", "model"), **_SHARD_MAP_KW)
+    f = jax.shard_map(body, mesh=mesh, in_specs=P("data", "model"),
+                      out_specs=P("data", "model"), check_vma=False)
     firm, cond = jaxpr_census(jax.make_jaxpr(f)(jnp.ones((2, 2))))
     m = frozenset({"model"})
     d = frozenset({"data"})
@@ -221,8 +221,9 @@ def test_check_moe_census_degenerate_mesh():
 
     from repro.analysis.trace_contract import check_moe_census
     from repro.core.partitioner import make_plan
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plan = make_plan("mixserve", mesh, comm_algo="fused",
                      dispatch="dropless")
     r = check_moe_census(_tiny_cfg(), plan)

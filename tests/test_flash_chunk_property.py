@@ -73,12 +73,12 @@ def test_flash_chunk_matches_oracle(head, batch):
 
 @pytest.mark.parametrize("head", sorted(HEAD_SHAPES))
 @settings(max_examples=25, deadline=None)
-@example(batch=(1, 4, [0], [0], 8))                       # all-idle
-@example(batch=(1, 6, [6], [5], 16))                      # single full slot
-@example(batch=(3, 4, [4, 1, 0], [0, 9, 0], 16))          # mixed step
-@example(batch=(2, 8, [3, 8], [13, 0], 24))               # ragged tails
+@example(batch=(1, 4, [0], [0], 8), seed=0)                # all-idle
+@example(batch=(1, 6, [6], [5], 16), seed=0)               # single full slot
+@example(batch=(3, 4, [4, 1, 0], [0, 9, 0], 16), seed=0)   # mixed step
+@example(batch=(2, 8, [3, 8], [13, 0], 24), seed=0)        # ragged tails
 @given(batch=ragged_batches(), seed=st.integers(0, 2 ** 16))
-def test_flash_chunk_paged_bit_identical_to_dense(head, batch, seed=0):
+def test_flash_chunk_paged_bit_identical_to_dense(head, batch, seed):
     """Page indirection through a PERMUTED block table never changes the
     bits: flash_chunk_paged == flash_chunk at the same bq/bs, for any
     ragged slot mix (the shared-prefix serving invariant)."""

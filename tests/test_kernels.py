@@ -456,6 +456,28 @@ def test_autotune_tune_measures_and_registers():
     autotune.clear_cache()
 
 
+def test_autotune_tune_reports_and_raises_on_failed_candidates():
+    """A candidate the compiler refuses is reported, not swallowed; the
+    survivors still compete, and no survivor at all is an error."""
+    autotune.clear_cache()
+
+    def fn(x, *, bn):
+        if bn == 7:
+            raise ValueError("block refused")
+        return x * bn
+
+    x = jnp.ones((4,), jnp.float32)
+    with pytest.warns(UserWarning, match="block refused"):
+        best = autotune.tune("permute", fn, [{"bn": 7}, {"bn": 8}], x,
+                             shape=(4, 4), dtype=jnp.float32)
+    assert best == {"bn": 8}
+    with pytest.warns(UserWarning), pytest.raises(RuntimeError,
+                                                  match="none of 1"):
+        autotune.tune("permute", fn, [{"bn": 7}], x, shape=(4, 4),
+                      dtype=jnp.float32)
+    autotune.clear_cache()
+
+
 def test_autotune_flash_decode_bs_tracks_kv_len():
     """The flash tile grows with the cache length S (k.shape[1]), not with
     the head dim."""

@@ -282,3 +282,48 @@ def test_cluster_for_mesh_explicit_and_fallback():
     assert cluster_for_mesh(FakeMesh(16), CLUSTERS["h20x16"]).name == "h20x16"
     with pytest.raises(ValueError):
         cluster_for_mesh(FakeMesh(8), "h20x16")     # 16 devices != 8
+
+
+def test_resolve_cluster_reads_device_kind_on_tpu(monkeypatch):
+    """With no mesh on a TPU backend the attached chip's device_kind picks
+    the cluster; a kind with no ClusterSpec is an error, not a default."""
+    import jax
+
+    from repro.core.resolve import resolve_cluster
+
+    class Dev:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev("TPU v5 lite")])
+    spec, prov = resolve_cluster()
+    assert spec.name == "v5e-1" and spec.n_devices == 1
+    assert spec.peak_flops == 197e12 and prov == "auto:device_kind(TPU v5 lite)"
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev("TPU v9 imaginary")])
+    with pytest.raises(KeyError, match="device kind"):
+        resolve_cluster()
+
+
+def test_compile_cache_env_wins_else_fixed_checkout_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and nothing is set
+    in code; otherwise the cache is <repo>/.jax_cache, which git ignores."""
+    import pathlib
+
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert pathlib.Path(path) == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()

@@ -46,3 +46,10 @@ def test_trace_contract_census_matches_cost_model():
     for every strategy/algorithm/overlap, and a sabotaged block fails."""
     out = _run("run_trace_contract.py")
     assert "TRACE_CONTRACT_OK" in out
+
+
+def test_engine_serves_on_mesh():
+    """LLM.from_spec(mesh=...) shards the weights by the plan and serves
+    the same greedy streams as the unsharded engine under every strategy."""
+    out = _run("run_engine_mesh.py")
+    assert "ENGINE_MESH_OK" in out
