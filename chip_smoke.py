@@ -207,7 +207,7 @@ def unified_args(eng):
     b, c = eng.max_batch, eng.chunk
     return (eng.params, jnp.zeros((b, c), jnp.int32),
             jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-            eng.cache, jax.random.PRNGKey(0))
+            (eng.cache, eng._ecnt), jax.random.PRNGKey(0))
 
 
 # ---------------------------------------------------------------------------

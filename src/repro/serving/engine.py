@@ -3,13 +3,15 @@ KV cache.
 
 Default path — ONE jitted program (the unified mixed prefill/decode step):
 
-  unified_fn(params, tokens(B, chunk), q_lens(B,), v_lens(B,), cache, key)
-      -> (tokens(B, n), n_emit(B,), last_logits(B, V), step_logits, cache,
-          bad(B,))
+  unified_fn(params, tokens(B, chunk), q_lens(B,), v_lens(B,),
+             (cache, expert_counts), key)
+      -> (tokens(B, n), n_emit(B,), last_logits(B, V), step_logits,
+          (cache, expert_counts), bad(B,))
   (n = 1 + speculation k; step_logits = every row's (B, chunk, V) logits
    under ``debug_logits``, else None — the hot path runs the LM head only
    on the selected rows; bad[i] flags a non-finite sampled-logits row, the
-   NaN/Inf quarantine signal)
+   NaN/Inf quarantine signal; expert_counts is the device-side (E,) int32
+   routed-slot accumulator that rides beside the KV state)
 
 Every iteration each slot contributes ``q_lens[i] ∈ {0, 1, …, chunk}``
 tokens against the fixed (B, chunk) buffer: a decoding slot contributes its
@@ -38,6 +40,12 @@ Robustness (docs/serving.md "Robustness & degradation"):
   ``ServeSpec.faults``) deterministically injects latency spikes, NaN
   rows, admit failures and clock skew for chaos testing.
 
+Tracing (docs/serving.md "Tracing"): each unified step is an
+``engine.step`` profiler span whose children name its host phases (plan,
+reap, pack, upload, launch, readback, commit), and ``step_stats`` keeps
+the same boundaries as counters.  With no profiler running a span costs
+about a microsecond.
+
 Legacy path — the pre-unified two-program engine (bucket-padded blocking
 prefill in ``admit`` + a separate decode program) survives ONLY for
 families the unified step cannot serve (``unified_supported`` False:
@@ -63,6 +71,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.cost_model import cap_rows_for
@@ -185,12 +194,30 @@ class Engine:
         self.debug_logits = bool(spec.debug_logits)
 
         # expert-load observability (MoE only, unified path): per-expert
-        # routed-slot counts summed over layers/steps plus the EP-exchange
-        # byte ledger — moved under the resolved count-bounded extent vs
-        # the monolithic worst case (``ep_load_stats``)
-        self.expert_counts = np.zeros((max(cfg.n_experts, 1),), np.int64)
+        # routed-slot counts summed over layers/steps, accumulated on the
+        # device by the unified step and read only when asked
+        # (``expert_counts``), plus the EP-exchange byte ledger — moved
+        # under the resolved count-bounded extent vs the monolithic worst
+        # case (``ep_load_stats``)
+        n_e = max(cfg.n_experts, 1)
+        self._ecnt = jnp.zeros((n_e,), jnp.int32)
+        self._ecnt_host = np.zeros((n_e,), np.int64)
+        # the int32 accumulator is folded into the host's int64 copy before
+        # it can overflow: a step routes at most every buffer row to top_k
+        # experts in every layer
+        per_step = self.max_batch * self.chunk * max(cfg.top_k, 1) \
+            * cfg.n_layers
+        self._ecnt_fold_every = max(1, (2 ** 31 - 1) // per_step)
+        self._ecnt_steps = 0
         self.a2a_bytes_moved = 0
         self.a2a_bytes_worst = 0
+        # step counters at the span boundaries (``step_stats``): rows fed
+        # and computed, the host's time per step outside the read-back,
+        # and the read-back waits
+        self._rows_fed = self._rows_buffer = 0
+        self._n_steps = 0
+        self._host_s = 0.0
+        self._readback_s = self._readback_max_s = 0.0
 
         # deterministic chaos harness (ServeSpec.faults); empty = inert
         self.faults = FaultInjector(getattr(spec, "faults", ()),
@@ -301,7 +328,7 @@ class Engine:
                 "ServeSpec.kv pool_pages (or leave kv='auto')")
 
     # -- jitted programs -------------------------------------------------
-    def _unified_impl(self, params, tokens, q_lens, v_lens, cache, key):
+    def _unified_impl(self, params, tokens, q_lens, v_lens, state, key):
         """THE serving program: one mixed token-budget iteration.
 
         tokens (B, chunk) int32, q_lens (B,) int32.  Slot i's valid rows are
@@ -310,10 +337,13 @@ class Engine:
         past q_lens[i] are inert.  ``v_lens[i]`` marks decode-phase slots
         (v = q: the rows are [last committed token, k drafts]); 0 means
         prefill/idle.  ``bad[i]`` flags a non-finite sampled-logits row on
-        a scheduled slot — the NaN/Inf quarantine signal.
+        a scheduled slot — the NaN/Inf quarantine signal.  ``state`` is the
+        pair (KV cache, expert_counts): the (E,) int32 routed-slot
+        accumulator rides beside the cache, in and out, so the counts stay
+        on the device until ``Engine.expert_counts`` asks for them.
 
         Returns (toks (B, n), n_emit (B,), last (B, V), step_logits,
-        cache, bad (B,), expert_counts) with n = 1 + spec_k.  Slot i's
+        (cache, expert_counts), bad (B,)) with n = 1 + spec_k.  Slot i's
         committed tokens are ``toks[i, :n_emit[i]]``: greedy verify accepts
         draft j+1 iff it equals the argmax of row j's logits (row j is the
         prediction after consuming rows 0..j), so the accepted drafts ARE
@@ -325,6 +355,7 @@ class Engine:
         non-speculative program (n_emit is 1 on every scheduled slot and
         temperature sampling applies).
         """
+        cache, counts = state
         n = self.n_logits
         if n == 1:                        # non-speculative: the PR 8 graph
             out = forward(params, self.cfg, self.plan, tokens=tokens,
@@ -346,7 +377,7 @@ class Engine:
                 nxt = jnp.argmax(last, -1)
             emit = jnp.where(q_lens > 0, 1, 0).astype(jnp.int32)
             return (nxt.astype(jnp.int32)[:, None], emit, last, step_logits,
-                    out.cache, bad, out.expert_counts)
+                    (out.cache, self._add_counts(counts, out)), bad)
 
         # speculative verify: score one logit row per draft position.  Row
         # j's logits are the model's prediction AFTER consuming rows 0..j
@@ -384,8 +415,13 @@ class Engine:
                          0).astype(jnp.int32)
         rollback = jnp.where(v_lens > 0, s - acc, 0).astype(jnp.int32)
         cache2 = {**out.cache, "length": out.cache["length"] - rollback}
-        return (greedy, emit, sel[:, 0], step_logits, cache2, bad,
-                out.expert_counts)
+        return (greedy, emit, sel[:, 0], step_logits,
+                (cache2, self._add_counts(counts, out)), bad)
+
+    @staticmethod
+    def _add_counts(counts, out):
+        return counts if out.expert_counts is None \
+            else counts + out.expert_counts
 
     def _prefill_impl(self, params, tokens, real_len):
         cache = init_cache(self.cfg, 1, self.max_len, self.dtype)
@@ -434,6 +470,10 @@ class Engine:
         the slot's cache length is zeroed.  Legacy path: the old blocking
         bucket-padded prefill.  Raises ``InjectedFault`` when an "admit"
         fault targets this request (the scheduler sheds it)."""
+        with TraceAnnotation("serve.admit", rid=req.rid):
+            return self._admit(req)
+
+    def _admit(self, req: Request) -> bool:
         self.validate(req)
         fault = self.faults.admit_blocked(self._step_idx, req.rid) \
             if self.faults else None
@@ -652,10 +692,29 @@ class Engine:
         """One engine iteration.  Returns retired requests (state DONE, or
         FAILED for NaN-quarantined slots).
 
-        Unified: one mixed token-budget step over all slots.  Legacy: one
-        decode step for all active (fully prefilled) slots."""
+        Unified: one mixed token-budget step over all slots, traced as an
+        ``engine.step`` span with the plan's row counts as its args.
+        Legacy: one decode step for all active (fully prefilled) slots."""
         if self.legacy:
             return self._step_legacy()
+        t0, rb0 = time.perf_counter(), self._readback_s
+        with TraceAnnotation("engine.step", step=self._step_idx,
+                             buffer=self.max_batch * self.chunk) as span:
+            with TraceAnnotation("engine.plan"):
+                q, preempted = self._plan_step(token_budget)
+            pre = sum(int(q[i]) for i, p in enumerate(self._pending)
+                      if p is not None and self._prompt_pos[i] < len(p))
+            span.set_metadata(rows=int(q.sum()), prefill_rows=pre,
+                              decode_rows=int(q.sum()) - pre,
+                              slots=self.n_active)
+            retired = preempted + self.unified_step(q)
+        self._n_steps += 1
+        self._host_s += (time.perf_counter() - t0
+                         - (self._readback_s - rb0))
+        return retired
+
+    def _plan_step(self, token_budget: Optional[int]) -> tuple:
+        """The step's per-slot plan, and the slots preempted to get one."""
         q = self.plan_q_lens(token_budget)
         preempted = []
         # paged-pool deadlock breaker: live slots exist but the pool could
@@ -672,7 +731,7 @@ class Engine:
                 break
             preempted.append(self.preempt(victim))
             q = self.plan_q_lens(token_budget)
-        return preempted + self.unified_step(q)
+        return q, preempted
 
     def _reap(self) -> list:
         """Sweep slots already retired (terminal state set by cancel/
@@ -706,7 +765,8 @@ class Engine:
         """Run the jitted unified step with an explicit per-slot plan."""
         step_idx = self._step_idx
         self._step_idx += 1
-        retired = self._reap()
+        with TraceAnnotation("engine.reap"):
+            retired = self._reap()
         q_lens = np.asarray(q_lens, np.int32)
         self.last_step_tokens = int(q_lens.sum())
         if self.faults:
@@ -715,6 +775,38 @@ class Engine:
                 time.sleep(spike)            # injected straggler
         if not q_lens.any():
             return retired
+        with TraceAnnotation("engine.pack"):
+            toks, v_lens = self._pack(q_lens)
+        with TraceAnnotation("engine.upload"):
+            self.key, sub = jax.random.split(self.key)
+            self.kv.flush()          # push dirty block tables to device
+            args = (jnp.asarray(toks), jnp.asarray(q_lens),
+                    jnp.asarray(v_lens))
+        with TraceAnnotation("engine.launch", step=step_idx):
+            (nxt, emit, self.last_logits, self.step_logits,
+             (self.cache, self._ecnt), bad) = self._unified(
+                self.params, *args, (self.cache, self._ecnt), sub)
+        # the step's only host reads, (B, n) tokens and two (B,) vectors,
+        # for request bookkeeping + the next step's token buffer (which
+        # must merge host-side prompt chunks anyway); the expert counts
+        # stay on the device
+        t0 = time.perf_counter()
+        with TraceAnnotation("engine.readback", step=step_idx):
+            nxt_host = np.asarray(nxt)
+            emit_host = np.asarray(emit)
+            bad_host = np.array(bad)   # copy: fault injection writes into it
+        wait = time.perf_counter() - t0
+        self._readback_s += wait
+        self._readback_max_s = max(self._readback_max_s, wait)
+        self._rows_fed += self.last_step_tokens
+        self._rows_buffer += toks.size
+        with TraceAnnotation("engine.commit"):
+            self._commit(step_idx, q_lens, v_lens, nxt_host, emit_host,
+                         bad_host, retired)
+        return retired
+
+    def _pack(self, q_lens: np.ndarray) -> tuple:
+        """The step's (B, chunk) token buffer and (B,) verify lengths."""
         toks = np.zeros((self.max_batch, self.chunk), np.int32)
         v_lens = np.zeros((self.max_batch,), np.int32)
         for i, r in enumerate(self.slots):
@@ -733,22 +825,18 @@ class Engine:
                 else:
                     v_lens[i] = min(n, 1)     # plain decode (stale/absent
                     #                           drafts never reach the step)
-        self.key, sub = jax.random.split(self.key)
-        self.kv.flush()          # push dirty block tables to device
-        (nxt, emit, self.last_logits, self.step_logits, self.cache, bad,
-         ecnt) = self._unified(self.params, jnp.asarray(toks),
-                               jnp.asarray(q_lens), jnp.asarray(v_lens),
-                               self.cache, sub)
-        if ecnt is not None:
-            self.expert_counts += np.asarray(ecnt, np.int64)
+        return toks, v_lens
+
+    def _commit(self, step_idx: int, q_lens, v_lens, nxt_host, emit_host,
+                bad_host, retired: list) -> None:
+        """Per-slot bookkeeping of a finished step: KV length mirror, the
+        a2a ledger, committed tokens, quarantine, retirement."""
+        if self.cfg.is_moe:
             self._account_a2a(int(q_lens.sum()))
+            self._ecnt_steps += 1
+            if self._ecnt_steps >= self._ecnt_fold_every:
+                self._fold_counts()
         self.kv.advance(q_lens)  # host length mirror follows the device
-        # one (B, n) host read per step, for request bookkeeping + the next
-        # step's token buffer (which must merge host-side prompt chunks
-        # anyway — the (B, chunk) int32 upload is noise next to the model)
-        nxt_host = np.asarray(nxt)
-        emit_host = np.asarray(emit)
-        bad_host = np.array(bad)       # copy: fault injection writes into it
         if self.faults:
             live = {i: r.rid for i, r in enumerate(self.slots)
                     if r is not None and q_lens[i] > 0}
@@ -808,7 +896,19 @@ class Engine:
                 if self.draft is not None:
                     self.draft.release(i)
         self._drafts = [None] * self.max_batch    # drafts are one-shot
-        return retired
+
+    def step_stats(self) -> dict:
+        """Step counters for ServeMetrics: the share of computed rows
+        (``max_batch * chunk`` per step) that carried a token, the host's
+        time per ``step`` outside the read-back, and the longest read-back
+        wait.  Counted at the ``engine.*`` span boundaries."""
+        return {
+            "fed_row_share": (self._rows_fed / self._rows_buffer
+                              if self._rows_buffer else 0.0),
+            "host_ms_per_step": (1e3 * self._host_s / self._n_steps
+                                 if self._n_steps else 0.0),
+            "readback_ms_max": 1e3 * self._readback_max_s,
+        }
 
     def spec_stats(self) -> dict:
         """Speculation counters for ServeMetrics / bench meta."""
@@ -824,6 +924,20 @@ class Engine:
         }
 
     # -- expert-load / EP-exchange observability -------------------------
+    @property
+    def expert_counts(self) -> np.ndarray:
+        """(E,) int64 routed-slot counts per expert, summed over layers and
+        steps.  One device read, here: the unified step accumulates the
+        counts on the device."""
+        self._fold_counts()
+        return self._ecnt_host.copy()
+
+    def _fold_counts(self) -> None:
+        """Move the device accumulator into the host's int64 counts."""
+        self._ecnt_host += np.asarray(self._ecnt, np.int64)
+        self._ecnt = jnp.zeros_like(self._ecnt)
+        self._ecnt_steps = 0
+
     def _account_a2a(self, step_tokens: int) -> None:
         """Price the step's EP exchange into the byte ledger.
 

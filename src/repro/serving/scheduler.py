@@ -34,7 +34,13 @@ queued or mid-generation at exit are counted in
 ``ServeMetrics.n_incomplete``, and ``metrics()`` is well-defined with zero
 finished requests.  ``ServeMetrics`` carries the robustness counters
 (shed/preempt/cancel/deadline-miss/fault) surfaced in ``row()`` and the
-``BENCH_serve_mixed`` meta.
+``BENCH_serve_mixed`` meta, and the engine's step counters
+(``Engine.step_stats``) in ``row()`` only.
+
+The loop's own phases are profiler spans beside the engine's:
+``serve.deadlines`` (step 1) and ``serve.wait`` (the sleep until the next
+arrival); admission is the engine's ``serve.admit`` (docs/serving.md
+"Tracing").
 """
 
 from __future__ import annotations
@@ -45,6 +51,7 @@ from collections import deque
 from typing import Iterable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.resolve import OverloadPolicy
 from repro.serving.engine import Engine, Request, RequestState
@@ -98,6 +105,10 @@ class ServeMetrics:
     n_spec_accepted: int = 0         # draft tokens accepted
     spec_accept_rate: float = 0.0    # accepted / drafted
     spec_tokens_per_step: float = 0.0  # committed tokens per verify step
+    # engine step counters (Engine.step_stats; docs/serving.md "Tracing")
+    fed_row_share: float = 0.0     # rows that carried a token / rows computed
+    host_ms_per_step: float = 0.0  # engine host time per step, read-back out
+    readback_ms_max: float = 0.0   # longest wait for a step's results
 
     def row(self) -> str:
         r = (f"n={self.n_requests} ttft={self.ttft_mean*1e3:.1f}ms "
@@ -119,6 +130,10 @@ class ServeMetrics:
         if self.n_spec_steps:
             r += (f" spec={self.spec_tokens_per_step:.2f}tok/step"
                   f"(acc {self.spec_accept_rate*100:.0f}%)")
+        if self.host_ms_per_step:
+            r += (f" fed={self.fed_row_share*100:.1f}% "
+                  f"host={self.host_ms_per_step:.2f}ms/step "
+                  f"readback_max={self.readback_ms_max:.1f}ms")
         if self.n_incomplete:
             r += f" INCOMPLETE={self.n_incomplete}"
         return r
@@ -297,7 +312,8 @@ class Scheduler:
         stall = 0
         while (self.waiting or self.engine.n_active) and steps < max_steps:
             now = self._now()
-            progress = self._enforce_deadlines(now) > 0
+            with TraceAnnotation("serve.deadlines"):
+                progress = self._enforce_deadlines(now) > 0
             progress |= self._admit_due(now) > 0
             if self.engine.n_active:
                 self.kv_peak = max(self.kv_peak, self.engine.kv.occupancy())
@@ -307,7 +323,8 @@ class Scheduler:
             elif self.waiting:
                 next_arrival = min(r.arrival for r in self.waiting)
                 if next_arrival > now:         # idle: wait for next arrival
-                    time.sleep(max(0.0, min(next_arrival - now, 1e-3)))
+                    with TraceAnnotation("serve.wait"):
+                        time.sleep(max(0.0, min(next_arrival - now, 1e-3)))
                     progress = True
             stall = 0 if progress else stall + 1
             if stall >= self.watchdog_steps:
@@ -375,6 +392,7 @@ class Scheduler:
             n_evictions=self.engine.kv.stats.n_evictions,
             **self.engine.ep_load_stats(),
             **self.engine.spec_stats(),
+            **self.engine.step_stats(),
         )
 
 
