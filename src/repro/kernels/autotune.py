@@ -40,6 +40,8 @@ import numpy as _np
 # f32 working-set budget per grid step; conservative half of the ~16 MB/core
 # VMEM so double-buffered pipelining of the next tiles fits alongside.
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+# the scoped VMEM a kernel may use on a v5e core unless it asks for more
+SCOPED_VMEM_BYTES = 2 * VMEM_BUDGET_BYTES
 _MXU = 128
 
 
@@ -188,6 +190,32 @@ def _fit(dim: int, cap: int, align: int = _MXU) -> int:
     return max(align, (b // align) * align)
 
 
+def grouped_gemm_vmem(bn: int, bh: int, bd: int, el: int) -> int:
+    """VMEM one ``grouped_gemm`` grid step holds as compiled: the x, w and
+    out blocks double-buffered, the f32 accumulator and the dot's f32
+    result, and the masked copy of the x block."""
+    x, w, out, acc = bn * bh * el, bh * bd * el, bn * bd * el, bn * bd * 4
+    return 2 * (x + w + out) + 2 * acc + x
+
+
+def _tiles(dim: int, align: int = _MXU) -> list:
+    """Candidate tiles for ``dim``, largest first: the MXU-aligned tiles
+    that divide it (the whole dim when it is at most one MXU width), or,
+    where no multiple of ``align`` divides it, ``_fit``'s tile and its
+    halvings, which the kernel wrapper pads up to."""
+    if dim <= align:
+        return [max(1, dim)]
+    tiles = [b for b in range(dim - dim % align, 0, -align) if dim % b == 0]
+    if tiles:
+        return tiles
+    b = _fit(dim, 512, align)
+    tiles = [b]
+    while b > align:
+        b = max(align, (b // 2) // align * align)
+        tiles.append(b)
+    return tiles
+
+
 def _default_blocks(op: str, shape: tuple, dtype: str) -> dict:
     el = _bytes(dtype)
     if op == "moe_gemm":
@@ -206,21 +234,24 @@ def _default_blocks(op: str, shape: tuple, dtype: str) -> dict:
                 bd = max(_MXU, bd // 2)
         return {"bc": bc, "bd": bd, "bh": bh}
     if op == "grouped_gemm":
+        # tiles that divide H and D, so the weights are read where they lie
+        # with no pad.  bh first: each grid step adds its (bn, bd) dot into
+        # the f32 accumulator and masks its (bn, bh) x block, so the
+        # accumulator's VMEM traffic per MXU pass falls as 1/bh and the
+        # mask's as 1/bd.  bh is the widest that fits beside a bd of at
+        # least 512 (or D's widest tile, if smaller); bd then grows into
+        # what is left.  bn stays at most 512: a smaller one also means
+        # fewer masked rows per boundary-straddling visit.
         n, h, d, _e = shape
-        bn, bh, bd = _fit(n, 512), _fit(h, 512), _fit(d, 512)
-        # same working-set shrink as moe_gemm: x(bn,bh)+w(bh,bd)+acc(bn,bd);
-        # smaller bn also means fewer masked rows per boundary-straddling
-        # tile visit, so don't grow bn past the ragged row count.
-        while (bn * bh * el + bh * bd * el + bn * bd * 4) > VMEM_BUDGET_BYTES:
-            m = max(bn, bh, bd)
-            if m <= _MXU:
-                break
-            if bn == m:
-                bn = max(_MXU, bn // 2)
-            elif bh == m:
-                bh = max(_MXU, bh // 2)
-            else:
-                bd = max(_MXU, bd // 2)
+        bn = _fit(n, 512)
+        limit = SCOPED_VMEM_BYTES - SCOPED_VMEM_BYTES // 8
+
+        def fits(bh, bd):
+            return grouped_gemm_vmem(bn, bh, bd, el) <= limit
+        hs, ds = _tiles(h), _tiles(d)
+        bd_min = min(b for b in ds if b >= min(512, ds[0]))
+        bh = next((b for b in hs if fits(b, bd_min)), hs[-1])
+        bd = next((b for b in ds if fits(bh, b)), ds[-1])
         return {"bn": bn, "bd": bd, "bh": bh}
     if op in ("permute", "unpermute"):
         # n output rows per grid step; the gather source stays in HBM (rows
@@ -325,7 +356,7 @@ def _key_shape(op: str, args: tuple) -> tuple:
     if op == "moe_gemm":                  # (x, w) -> (E, C, H, D)
         return tuple(args[0].shape) + (args[1].shape[-1],)
     if op == "grouped_gemm":              # (x, w, offsets) -> (N, H, D, E)
-        return tuple(args[0].shape) + (args[1].shape[-1], args[1].shape[0])
+        return tuple(args[0].shape) + (args[1].shape[-1], args[1].shape[-3])
     if op in ("permute", "unpermute"):    # (x|buf, idx, ...) -> (N|T, h)
         return (args[1].shape[0], args[0].shape[-1])
     if op == "flash_decode":              # (q, k, v, lens) -> k.shape
@@ -386,4 +417,5 @@ def tune(op: str, fn: Callable, candidates: list[dict], *args,
 
 __all__ = ["select_blocks", "lookup", "register", "tune", "cache_info",
            "clear_cache", "cache_key", "cache_path", "load_persistent",
-           "CACHE_VERSION", "KernelKey", "VMEM_BUDGET_BYTES"]
+           "CACHE_VERSION", "KernelKey", "VMEM_BUDGET_BYTES",
+           "SCOPED_VMEM_BYTES", "grouped_gemm_vmem"]

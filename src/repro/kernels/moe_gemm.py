@@ -16,7 +16,10 @@ grid steps; HBM->VMEM streaming of x/w tiles, MXU dot per step):
                 constraints), so compute volume is sum(counts) = N = T*k rows
                 — independent of E and of the capacity factor.  Segment
                 boundaries are dynamic *values* (static shapes), delivered to
-                the index maps through scalar prefetch.
+                the index maps through scalar prefetch.  The weights may
+                be one layer's (E, H, D) experts or the whole (L, E, H, D)
+                layer stack with a scalar layer index (scalar-prefetched
+                too), which a layer scan hands over without slicing it.
 
 TPU adaptation (DESIGN.md §2): the paper's NPU/GPU expert GEMMs become one
 MXU-tiled grouped GEMM over the dispatch buffers that the fused AR-A2A
@@ -126,7 +129,9 @@ def _group_metadata(group_offsets, n: int, bn: int, e: int,
     return jnp.stack([tile_ids, group_ids, row_start, row_end, first])
 
 
-def _grouped_gemm_kernel(meta_ref, x_ref, w_ref, o_ref, acc_ref, *, bn: int):
+def _grouped_gemm_kernel(meta_ref, layer_ref, x_ref, w_ref, o_ref, acc_ref,
+                         *, bn: int):
+    del layer_ref                     # read by the weight index map only
     vi = pl.program_id(1)
 
     @pl.when(pl.program_id(2) == 0)
@@ -137,7 +142,7 @@ def _grouped_gemm_kernel(meta_ref, x_ref, w_ref, o_ref, acc_ref, *, bn: int):
             + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0))
     mask = (rows >= meta_ref[2, vi]) & (rows < meta_ref[3, vi])
     xt = jnp.where(mask, x_ref[...], jnp.zeros_like(x_ref[...]))
-    acc_ref[...] += jnp.dot(xt, w_ref[0], preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(xt, w_ref[...], preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _flush():
@@ -147,9 +152,14 @@ def _grouped_gemm_kernel(meta_ref, x_ref, w_ref, o_ref, acc_ref, *, bn: int):
         o_ref[...] = jnp.where(meta_ref[4, vi] == 1, acc, o_ref[...] + acc)
 
 
+def w_pad(h: int, d: int, bh: int, bd: int) -> bool:
+    """Whether ``grouped_gemm`` pads its weight operand at these blocks."""
+    return bool((-h) % min(bh, h) or (-d) % min(bd, d))
+
+
 @functools.partial(jax.jit, static_argnames=("bn", "bd", "bh", "interpret"))
-def grouped_gemm(x, w, group_offsets, *, bn: int = 128, bd: int = 128,
-                 bh: int = 128, interpret: bool = False):
+def grouped_gemm(x, w, group_offsets, layer=None, *, bn: int = 128,
+                 bd: int = 128, bh: int = 128, interpret: bool = False):
     """x (N, H) sorted by expert @ w (E, H, D) -> (N, D), per-segment.
 
     ``group_offsets`` (E+1,) int32 ascending prefix-sum: expert e owns rows
@@ -157,15 +167,27 @@ def grouped_gemm(x, w, group_offsets, *, bn: int = 128, bd: int = 128,
     segment; their output is exact zeros (the ragged EP exchange pads with
     zero rows that callers never read, but the kernel still writes every
     block — no uninitialized output memory).
+
+    ``w`` may also be a layer stack (L, E, H, D) with a scalar ``layer``:
+    the kernel then reads that layer's experts where they lie, through the
+    weight block's index map, so no per-layer copy of the stack is made.
+    Blocks that do not divide H or D make the wrapper pad ``w`` — for a
+    stack, one layer sliced out and padded.
     """
     n, h = x.shape
-    e, _, d = w.shape
+    if w.ndim == 3:
+        w, layer = w[None], 0
+    elif layer is None:
+        raise ValueError("a (L, E, H, D) weight stack needs its layer index")
+    n_layers, e, _, d = w.shape
+    layer = jnp.clip(jnp.asarray(layer, jnp.int32), 0, n_layers - 1)
     bn, bh, bd = min(bn, n), min(bh, h), min(bd, d)
     pn, ph, pd = (-n) % bn, (-h) % bh, (-d) % bd
     if pn or ph:
         x = jnp.pad(x, ((0, pn), (0, ph)))
     if ph or pd:
-        w = jnp.pad(w, ((0, 0), (0, ph), (0, pd)))
+        w = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=True)
+        w, layer = jnp.pad(w, ((0, 0), (0, 0), (0, ph), (0, pd))), 0
     np_, hp, dp = n + pn, h + ph, d + pd
     num_tiles = np_ // bn
     # static visit bound: every tile once + one extra visit per boundary
@@ -174,15 +196,15 @@ def grouped_gemm(x, w, group_offsets, *, bn: int = 128, bd: int = 128,
                            num_tiles, n_visits)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(dp // bd, n_visits, hp // bh),
         in_specs=[
-            pl.BlockSpec((bn, bh), lambda di, vi, hi, m: (m[0, vi], hi)),
-            pl.BlockSpec((1, bh, bd),
-                         lambda di, vi, hi, m: (m[1, vi], hi, di)),
+            pl.BlockSpec((bn, bh), lambda di, vi, hi, m, l: (m[0, vi], hi)),
+            pl.BlockSpec((None, None, bh, bd),
+                         lambda di, vi, hi, m, l: (l[0], m[1, vi], hi, di)),
         ],
         out_specs=pl.BlockSpec((bn, bd),
-                               lambda di, vi, hi, m: (m[0, vi], di)),
+                               lambda di, vi, hi, m, l: (m[0, vi], di)),
         scratch_shapes=[pltpu.VMEM((bn, bd), jnp.float32)],
     )
     out = pl.pallas_call(
@@ -190,7 +212,7 @@ def grouped_gemm(x, w, group_offsets, *, bn: int = 128, bd: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((np_, dp), x.dtype),
         interpret=interpret,
-    )(meta, x, w)
+    )(meta, jnp.reshape(layer, (1,)).astype(jnp.int32), x, w)
     return out[:n, :d]
 
 

@@ -25,6 +25,7 @@ from repro.kernels.flash_chunk import flash_chunk_paged as _flash_chunk_paged
 from repro.kernels.flash_decode import flash_decode as _flash_decode
 from repro.kernels.moe_gemm import grouped_gemm as _grouped_gemm
 from repro.kernels.moe_gemm import moe_gemm as _moe_gemm
+from repro.kernels.moe_gemm import w_pad
 from repro.kernels.permute import permute_tokens as _permute_tokens
 from repro.kernels.permute import (permute_tokens_ragged
                                    as _permute_tokens_ragged)
@@ -53,14 +54,22 @@ def moe_gemm(x, w, **kw):
     return _moe_gemm(x, w, **kw)
 
 
-def grouped_gemm(x, w, group_offsets, **kw):
+def grouped_gemm(x, w, group_offsets, layer=None, **kw):
+    """``w`` is (E, H, D), or the (L, E, H, D) layer stack with ``layer``.
+
+    Besides the call site, counts the sites that read the stack in place
+    (``grouped_gemm_stacked``) and those whose wrapper pads ``w``
+    (``grouped_gemm_w_pad``)."""
     counters["grouped_gemm"] += 1
     kw.setdefault("interpret", _interpret())
+    n, h = x.shape
     if not {"bn", "bd", "bh"} & kw.keys():
-        n, h = x.shape
         kw.update(autotune.select_blocks(
-            "grouped_gemm", (n, h, w.shape[-1], w.shape[0]), x.dtype))
-    return _grouped_gemm(x, w, group_offsets, **kw)
+            "grouped_gemm", (n, h, w.shape[-1], w.shape[-3]), x.dtype))
+    padded = w_pad(h, w.shape[-1], kw.get("bh", 128), kw.get("bd", 128))
+    counters["grouped_gemm_w_pad"] += padded
+    counters["grouped_gemm_stacked"] += w.ndim == 4 and not padded
+    return _grouped_gemm(x, w, group_offsets, layer, **kw)
 
 
 def topk_gate(logits, k: int, **kw):

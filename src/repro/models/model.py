@@ -613,14 +613,26 @@ def forward(params, cfg: ModelConfig, plan: ShardingPlan = NULL_PLAN, *,
     for gi, g in enumerate(layer_plan(cfg)):
         p_g = params["groups"][gi]
         c_g = None if cache is None else cache["groups"][gi]
+        # the expert GEMM kernel reads each layer's experts out of the
+        # stack: the scan closes over the stacks (loop-invariant, never
+        # sliced) and carries the layer index in their place
+        stack = {}
+        if g.kind == "moe" and not remat and MOE.reads_stack(plan):
+            moe_p = dict(p_g["moe"])
+            stack = {k: moe_p.pop(k) for k in MOE.EXPERT_WEIGHTS
+                     if k in moe_p}
+            moe_p["layer"] = jnp.arange(g.count, dtype=jnp.int32)
+            p_g = {**p_g, "moe": moe_p}
 
-        def body(carry, xs, _g=g):
+        def body(carry, xs, _g=g, _stack=stack):
             if expert_stats:
                 x, aux, cnt = carry
             else:
                 x, aux = carry
                 cnt = None
             p_l, c_l = xs
+            if _stack:
+                p_l = {**p_l, "moe": {**p_l["moe"], **_stack}}
 
             def apply(kind, pp, xx, cc):
                 r = apply_sublayer(kind, pp, xx, cc, cfg=cfg, plan=plan,

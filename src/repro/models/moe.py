@@ -301,14 +301,27 @@ def combine_rows(buf, src_slot, weights, use_kernel: bool = False):
     return _kref.unpermute_tokens_ref(buf, src_slot, weights)
 
 
+EXPERT_WEIGHTS = ("w_in", "w_gate", "w_out")
+
+
+def reads_stack(plan: ShardingPlan) -> bool:
+    """Whether the MoE layer scan hands ``grouped_gemm`` the layer-stacked
+    expert weights (``EXPERT_WEIGHTS``) and a ``"layer"`` index in place of
+    one layer's slice: the local dropless path with the kernel on."""
+    return (not plan.enabled and plan.kernels.moe_gemm
+            and resolve_dispatch(plan.dispatch_mode) == "dropless")
+
+
 def grouped_ffn(p, xs, offsets, cfg: ModelConfig, use_kernel: bool = False):
     """Expert FFN over a ragged buffer.  xs (N, h) sorted by expert,
     offsets (E_local+1,) -> (N, h).  Rows at/after offsets[-1] are
     unspecified (never read).  Partial sum over TP shards when de' < de,
-    exactly like ``expert_ffn``."""
+    exactly like ``expert_ffn``.  With a ``"layer"`` entry in ``p`` (the
+    kernel path only, ``reads_stack``) the expert weights are the
+    (L, E, h, de) layer stacks and the kernel reads that layer in place."""
     if use_kernel:
         from repro.kernels import ops as _kops
-        gg = _kops.grouped_gemm
+        gg = functools.partial(_kops.grouped_gemm, layer=p.get("layer"))
     else:
         from repro.kernels import ref as _kref
         gg = _kref.grouped_gemm_ref
@@ -932,5 +945,5 @@ __all__ = [
     "capacity_for", "positions_in_expert", "DispatchInfo",
     "dispatch_src_tok", "dispatch_src_slot",
     "resolve_dispatch", "DroplessInfo", "make_dropless", "gather_rows",
-    "combine_rows", "grouped_ffn",
+    "combine_rows", "grouped_ffn", "EXPERT_WEIGHTS", "reads_stack",
 ]

@@ -75,6 +75,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.cost_model import cap_rows_for
+from repro.kernels import ops as kops
 from repro.models.model import forward, init_cache
 from repro.serving.draft import make_draft
 from repro.serving.faults import FaultInjector, InjectedFault
@@ -218,6 +219,8 @@ class Engine:
         self._n_steps = 0
         self._host_s = 0.0
         self._readback_s = self._readback_max_s = 0.0
+        # kernel call sites in the traced step program (``_step_forward``)
+        self._step_sites: Counter = Counter()
 
         # deterministic chaos harness (ServeSpec.faults); empty = inert
         self.faults = FaultInjector(getattr(spec, "faults", ()),
@@ -328,6 +331,14 @@ class Engine:
                 "ServeSpec.kv pool_pages (or leave kv='auto')")
 
     # -- jitted programs -------------------------------------------------
+    def _step_forward(self, params, **kw):
+        """``forward`` inside the step program; keeps the kernel call sites
+        its trace counted (``kernels.ops.counters``) for ``step_stats``."""
+        before = kops.counters.copy()
+        out = forward(params, self.cfg, self.plan, **kw)
+        self._step_sites = kops.counters - before
+        return out
+
     def _unified_impl(self, params, tokens, q_lens, v_lens, state, key):
         """THE serving program: one mixed token-budget iteration.
 
@@ -358,10 +369,10 @@ class Engine:
         cache, counts = state
         n = self.n_logits
         if n == 1:                        # non-speculative: the PR 8 graph
-            out = forward(params, self.cfg, self.plan, tokens=tokens,
-                          cache=cache, q_lens=q_lens,
-                          last_only=not self.debug_logits,
-                          expert_stats=self.cfg.is_moe)
+            out = self._step_forward(params, tokens=tokens,
+                                     cache=cache, q_lens=q_lens,
+                                     last_only=not self.debug_logits,
+                                     expert_stats=self.cfg.is_moe)
             if self.debug_logits:
                 last = jnp.take_along_axis(
                     out.logits, jnp.maximum(q_lens - 1, 0)[:, None, None],
@@ -390,11 +401,12 @@ class Engine:
         rows = jnp.where((v_lens > 0)[:, None],
                          jnp.minimum(j[None, :], last_row[:, None]),
                          last_row[:, None])                 # (B, n)
-        out = forward(params, self.cfg, self.plan, tokens=tokens,
-                      cache=cache, q_lens=q_lens,
-                      last_only=not self.debug_logits,
-                      logit_rows=None if self.debug_logits else rows,
-                      expert_stats=self.cfg.is_moe)
+        out = self._step_forward(params, tokens=tokens,
+                                 cache=cache, q_lens=q_lens,
+                                 last_only=not self.debug_logits,
+                                 logit_rows=None if self.debug_logits
+                                 else rows,
+                                 expert_stats=self.cfg.is_moe)
         if self.debug_logits:
             sel = jnp.take_along_axis(out.logits, rows[:, :, None], axis=1)
             step_logits = out.logits
@@ -901,8 +913,16 @@ class Engine:
         """Step counters for ServeMetrics: the share of computed rows
         (``max_batch * chunk`` per step) that carried a token, the host's
         time per ``step`` outside the read-back, and the longest read-back
-        wait.  Counted at the ``engine.*`` span boundaries."""
+        wait, counted at the ``engine.*`` span boundaries; and, from the
+        trace of the step program, the share of its ``grouped_gemm`` call
+        sites that read the layer-stacked expert weights in place and the
+        count of sites whose wrapper padded the weights."""
+        sites = self._step_sites
         return {
+            "grouped_gemm_stacked_share": (
+                sites["grouped_gemm_stacked"] / sites["grouped_gemm"]
+                if sites["grouped_gemm"] else 0.0),
+            "grouped_gemm_w_pad": sites["grouped_gemm_w_pad"],
             "fed_row_share": (self._rows_fed / self._rows_buffer
                               if self._rows_buffer else 0.0),
             "host_ms_per_step": (1e3 * self._host_s / self._n_steps

@@ -109,6 +109,10 @@ class ServeMetrics:
     fed_row_share: float = 0.0     # rows that carried a token / rows computed
     host_ms_per_step: float = 0.0  # engine host time per step, read-back out
     readback_ms_max: float = 0.0   # longest wait for a step's results
+    # grouped_gemm call sites of the traced step program: the share that
+    # read the layer-stacked experts in place, and those that padded w
+    grouped_gemm_stacked_share: float = 0.0
+    grouped_gemm_w_pad: int = 0
 
     def row(self) -> str:
         r = (f"n={self.n_requests} ttft={self.ttft_mean*1e3:.1f}ms "

@@ -1,6 +1,7 @@
 """Pallas kernels integrated into the MoE block: kernel path == jnp path,
 locally (in-process) and on a CPU mesh (subprocess, fake devices)."""
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -11,8 +12,10 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ModelConfig
+from repro.core.partitioner import NULL_PLAN
 from repro.kernels import ops
 from repro.kernels.policy import KernelPolicy
+from repro.models import model as MD
 from repro.models import moe as M
 from repro.models.param import init_tree
 
@@ -22,6 +25,7 @@ CFG = ModelConfig(name="k-moe", family="moe", n_layers=1, d_model=64,
 
 HERE = os.path.dirname(__file__)
 SRC = os.path.join(HERE, "..", "src")
+ROOT = os.path.dirname(os.path.abspath(HERE))
 
 
 @pytest.mark.kernels
@@ -78,6 +82,37 @@ def test_moe_capacity_factor_zero_not_silently_replaced():
     np.testing.assert_allclose(np.asarray(out_zero), np.asarray(out_eps),
                                atol=1e-6)
     assert float(jnp.max(jnp.abs(out_zero - out_default))) > 1e-4
+
+
+@pytest.mark.parametrize("conf", ["phi3.5-moe-42b-l4", "deepseek-v2-lite-l9"])
+def test_unified_step_reads_expert_stacks_in_place(conf):
+    """The unified serving step of each benchmark configuration, traced at
+    its published widths over abstract params (nothing computed): every
+    ``grouped_gemm`` call site reads the layer-stacked experts in place
+    and none pads them."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from bench import arch
+
+    a = arch.arch_from(arch.load_config(f"bench/configs/{conf}.json"))
+    cfg = arch.program_config(a)
+    off = {k: False for k in a.overrides.get("kernels_off", ())}
+    plan = dataclasses.replace(
+        NULL_PLAN, kernels=dataclasses.replace(KernelPolicy.all_on(), **off))
+    assert M.reads_stack(plan)
+    b, chunk, max_len, page = 8, 64, 3584, 16
+    params = MD.abstract_params(cfg, jnp.bfloat16)
+    cache = jax.eval_shape(lambda: MD.init_paged_cache(
+        cfg, b, max_len, page_size=page, pool_pages=b * max_len // page))
+    ops.reset_counters()
+    jax.make_jaxpr(lambda p, t, c, q: MD.forward(
+        p, cfg, plan, tokens=t, cache=c, q_lens=q, last_only=True,
+        expert_stats=True))(params, jax.ShapeDtypeStruct((b, chunk), jnp.int32),
+                            cache, jax.ShapeDtypeStruct((b,), jnp.int32))
+    # one scan body traces the MoE layers' three GEMMs once
+    assert ops.counters["grouped_gemm"] == 3, dict(ops.counters)
+    assert ops.counters["grouped_gemm_stacked"] == 3
+    assert ops.counters["grouped_gemm_w_pad"] == 0
 
 
 @pytest.mark.parametrize("via_block", [False, True])

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import autotune, ops
+from repro.kernels.moe_gemm import w_pad
 
 pytestmark = pytest.mark.kernels
 
@@ -359,6 +360,92 @@ def test_grouped_gemm_partial_buffer_zero_tail():
                                np.asarray(want[:m_real]), atol=1e-5)
     assert float(jnp.max(jnp.abs(got[m_real:]))) == 0.0
     assert not bool(jnp.isnan(got).any())
+
+
+def _segments(n, e, skew, rng):
+    if skew == "one_expert":
+        counts = np.zeros(e, np.int64)
+        counts[e // 2] = n
+    elif skew == "empty_experts":
+        counts = rng.multinomial(n, [2 / e if i % 2 else 0.0
+                                     for i in range(e)])
+    else:
+        counts = rng.multinomial(n, np.ones(e) / e)
+    return jnp.asarray(np.concatenate([[0], np.cumsum(counts)]), jnp.int32)
+
+
+@pytest.mark.parametrize("n,m_real,h,d,e,skew", [
+    (64, 64, 128, 640, 4, "uniform"),     # 640: 128 divides it, 512 not
+    (96, 70, 256, 640, 5, "empty_experts"),   # zero tail past offsets[-1]
+    (48, 48, 128, 1408, 3, "one_expert"),     # 1408 = 11 x 128
+    (80, 57, 1408, 128, 6, "uniform"),        # 1408 along H, zero tail
+])
+def test_grouped_gemm_stacked_layer(n, m_real, h, d, e, skew):
+    """The (L, E, H, D) layer stack with a layer index == the oracle on
+    w[layer], with the default tiles, which divide every width here: the
+    weights are read in place, never padded."""
+    n_layers = 3
+    rng = np.random.default_rng(n + d)
+    offs = _segments(m_real, e, skew, rng)
+    x = np.zeros((n, h), np.float32)
+    x[:m_real] = rng.standard_normal((m_real, h))
+    x = jnp.asarray(x, jnp.bfloat16)
+    w = (jax.random.normal(jax.random.PRNGKey(1), (n_layers, e, h, d),
+                           jnp.float32) / np.sqrt(h)).astype(jnp.bfloat16)
+    ops.reset_counters()
+    for layer in range(n_layers):
+        got = ops.grouped_gemm(x, w, offs, jnp.int32(layer))
+        want = ops.grouped_gemm_ref(x, w[layer], offs)
+        assert got.shape == (n, d) and got.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(got[:m_real], np.float32),
+                                   np.asarray(want[:m_real], np.float32),
+                                   atol=2e-2, rtol=2e-2)
+        assert not np.asarray(got[m_real:], np.float32).any()
+    assert ops.counters["grouped_gemm_stacked"] == n_layers
+    assert ops.counters["grouped_gemm_w_pad"] == 0
+
+
+def test_grouped_gemm_stacked_pad_fallback():
+    """A width that no multiple of 128 divides: the wrapper pads one layer
+    sliced out of the stack, and still matches the oracle."""
+    n, h, d, e, n_layers = 40, 96, 200, 3, 2
+    offs = _segments(n, e, "uniform", np.random.default_rng(2))
+    x = jax.random.normal(KEY, (n, h), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (n_layers, e, h, d),
+                          jnp.float32) / np.sqrt(h)
+    ops.reset_counters()
+    got = ops.grouped_gemm(x, w, offs, 1)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ops.grouped_gemm_ref(x, w[1], offs)),
+                               atol=1e-5, rtol=1e-5)
+    assert ops.counters["grouped_gemm_w_pad"] == 1
+    assert ops.counters["grouped_gemm_stacked"] == 0
+
+
+@pytest.mark.parametrize("shape", [
+    (1024, 4096, 6400, 16), (1024, 6400, 4096, 16),     # phi3.5-moe
+    (3072, 2048, 1408, 64), (3072, 1408, 2048, 64),     # DeepSeek-V2-Lite
+    (1024, 5120, 1536, 160),                            # DeepSeek-V2
+    (512, 4096, 200, 16), (64, 1000, 1000, 4),          # no 128 divides
+])
+def test_grouped_gemm_default_blocks_divide_widths(shape):
+    n, h, d, _e = shape
+    b = autotune._default_blocks("grouped_gemm", shape, "bfloat16")
+    bn, bh, bd = b["bn"], b["bh"], b["bd"]
+    assert n % bn == 0
+    assert (autotune.grouped_gemm_vmem(bn, bh, bd, 2)
+            <= autotune.SCOPED_VMEM_BYTES * 7 // 8)
+    # x, w and f32 accumulator tiles within the per-step working-set budget
+    assert (bn * bh * 2 + bh * bd * 2 + bn * bd * 4
+            <= autotune.VMEM_BUDGET_BYTES)
+    divisible = h % 128 == 0 and d % 128 == 0
+    assert w_pad(h, d, bh, bd) == (not divisible)
+    if divisible:                # a wide contraction and a full MXU pass
+        assert bh >= 512 and bd >= 512
+    for dim, blk in ((h, bh), (d, bd)):
+        assert blk % 128 == 0 and blk <= dim - dim % 128
+        if dim % 128 == 0:
+            assert dim % blk == 0
 
 
 @pytest.mark.parametrize("t,h,n,fill", [

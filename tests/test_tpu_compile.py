@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels import autotune
 from repro.kernels import flash_chunk as fc
 from repro.kernels import moe_gemm as mg
 from repro.kernels import permute as pm
@@ -47,6 +48,14 @@ def one_chip():
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+def _gg_blocks(args):
+    x, w = args[0], args[1]
+    blocks = autotune.select_blocks(
+        "grouped_gemm", x.shape + (w.shape[-1], w.shape[-3]), x.dtype)
+    assert not mg.w_pad(x.shape[1], w.shape[-1], blocks["bh"], blocks["bd"])
+    return blocks
 
 
 # name -> (kernel call, argument shapes/dtypes); block sizes as the
@@ -81,6 +90,23 @@ CASES = {
     "grouped_gemm_5120x1536": (      # deepseek-v2 expert width
         lambda *a: mg.grouped_gemm(*a, bn=256, bd=512, bh=512),
         [((1024, 5120), BF16), ((20, 5120, 1536), BF16), ((21,), I32)]),
+    # the layer stack read in place, at the autotune default tiles
+    "grouped_gemm_stacked_phi35_4096x6400": (
+        lambda *a: mg.grouped_gemm(*a, **_gg_blocks(a)),
+        [((1024, 4096), BF16), ((4, 16, 4096, 6400), BF16), ((17,), I32),
+         ((), I32)]),
+    "grouped_gemm_stacked_phi35_6400x4096": (
+        lambda *a: mg.grouped_gemm(*a, **_gg_blocks(a)),
+        [((1024, 6400), BF16), ((4, 16, 6400, 4096), BF16), ((17,), I32),
+         ((), I32)]),
+    "grouped_gemm_stacked_lite_2048x1408": (
+        lambda *a: mg.grouped_gemm(*a, **_gg_blocks(a)),
+        [((3072, 2048), BF16), ((8, 64, 2048, 1408), BF16), ((65,), I32),
+         ((), I32)]),
+    "grouped_gemm_stacked_lite_1408x2048": (
+        lambda *a: mg.grouped_gemm(*a, **_gg_blocks(a)),
+        [((3072, 1408), BF16), ((8, 64, 1408, 2048), BF16), ((65,), I32),
+         ((), I32)]),
     "permute_tokens": (
         lambda *a: pm.permute_tokens(*a, bn=64),
         [((512, 4096), BF16), ((512,), I32)]),

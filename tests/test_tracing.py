@@ -135,6 +135,26 @@ def test_counters_reach_the_metrics_row(served):
     assert "fed_row_share" not in m.robustness()
 
 
+@pytest.mark.parametrize("moe_gemm", [True, False])
+def test_step_stats_count_stacked_expert_reads(moe, moe_gemm):
+    """From the trace of the step program: with the expert GEMM kernel on,
+    every ``grouped_gemm`` call site reads the layer-stacked experts in
+    place and none pads them; with it off there are no such sites."""
+    from repro.kernels.policy import KernelPolicy
+    cfg, params = moe
+    eng = make_engine(cfg, params, max_batch=2, max_len=64, chunk=8,
+                      kernels=KernelPolicy(moe_gemm=moe_gemm))
+    sched = Scheduler(eng)
+    sched.submit(Request(rid=0, prompt=np.arange(9, dtype=np.int32),
+                         max_new_tokens=2))
+    sched.run()
+    m = sched.metrics()
+    assert m.grouped_gemm_stacked_share == (1.0 if moe_gemm else 0.0)
+    assert m.grouped_gemm_w_pad == 0
+    assert eng.step_stats()["grouped_gemm_stacked_share"] \
+        == m.grouped_gemm_stacked_share
+
+
 def _stepped(moe, n, read_each, fold_every=None):
     cfg, params = moe
     eng = make_engine(cfg, params, max_batch=2, max_len=64, chunk=8)
